@@ -38,13 +38,9 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding
-
-try:  # jax 0.4.x
-    from jax import core as jax_core
-except ImportError:  # pragma: no cover - newer jax moves core
-    from jax.extend import core as jax_core  # type: ignore
 
 COLLECTIVE_PRIMS = {
     "psum", "pmin", "pmax", "all_gather", "all_to_all",
@@ -151,8 +147,7 @@ def trace_region(shard_fn, args, axis_env: dict, W: int):
     under; a collective's ring spans the axes it binds, and the axes it
     does NOT bind multiply into independent rings (ring_count).
     """
-    with jax_core.extend_axis_env_nd(list(axis_env.items())):
-        closed = jax.make_jaxpr(shard_fn)(*args)
+    closed = jax.make_jaxpr(shard_fn, axis_env=list(axis_env.items()))(*args)
     collectives, callbacks = [], []
     for idx, eqn in enumerate(_walk(closed.jaxpr)):
         name = eqn.primitive.name

@@ -78,6 +78,9 @@ class EngineStats(StatsBase):
     spec_rounds: int = 0
     spec_fallbacks: int = 0
     spec_discarded: int = 0
+    # frontier-step dispatches that ran the fused Pallas kernels
+    # (repro.kernels.frontier) rather than a jnp step
+    fused_steps: int = 0
 
 
 class ClosureEngine:
@@ -94,10 +97,10 @@ class ClosureEngine:
         reduce_impl: str | None = None,
         block_n: int | None = None,
         max_batch: int | None = None,
-        interpret: bool = True,
     ):
         # ``backend`` supersedes the old ``use_kernel`` flag:
-        #   kernel — Pallas closure kernel (interpret-mode on CPU)
+        #   kernel — Pallas closure kernel (compiled on TPU, interpreted on
+        #            CPU — repro.kernels.mosaic decides from the platform)
         #   jnp    — fused-jnp reference (fastest on CPU/XLA)
         #   matmul — MXU complement-counting closure (§Perf C2)
         if backend is None:
@@ -145,7 +148,6 @@ class ClosureEngine:
         self.reduce_impl = plan.reduce_impl
         self.block_n = plan.block_n
         self.max_batch = plan.max_batch
-        self.interpret = interpret
         self.stats = EngineStats(
             auto_hop_bytes=plan.auto_hop_bytes,
             hop_calibrated=plan.hop_calibrated,
@@ -172,7 +174,7 @@ class ClosureEngine:
     def _local_closure(self):
         """Per-shard map phase for the configured backend."""
         ctx = self.ctx
-        backend, block_n, interp = self.backend, self.block_n, self.interpret
+        backend, block_n = self.backend, self.block_n
 
         if backend == "matmul":
 
@@ -194,7 +196,6 @@ class ClosureEngine:
                     n_valid_rows=rows_local.shape[0],  # global pad corrected later
                     block_n=block_n,
                     use_kernel=backend == "kernel",
-                    interpret=interp,
                 )
 
         return local_closure
@@ -328,8 +329,8 @@ class ClosureEngine:
     #     fused filter kernel (pad correction + iceberg cut + canonicity in
     #     one pass).
     #
-    # Survivor *compaction* stays jnp in both placements: the argsort
-    # permutation is XLA's job and consumes only the kernel's keep mask —
+    # Survivor *compaction* stays jnp in both placements: the stable
+    # partition permutation is XLA's job and consumes only the kernel's keep mask —
     # identical masks in, identical order out, which is what makes the
     # fused steps bit-identical to the jnp builders (tests/
     # test_fused_frontier.py).  Call signatures match the jnp builders
@@ -342,7 +343,7 @@ class ClosureEngine:
             jnp.asarray(self._mask_np[None, :]),
             jnp.asarray(LOW),
             self.n_pad_rows,
-            dict(block_n=self.plan.block_n, interpret=self.interpret),
+            dict(block_n=self.plan.block_n),
             _compact,
             _sort_unique,
         )
@@ -409,7 +410,6 @@ class ClosureEngine:
             )
 
         # multi-shard: map kernel → collectives → fused filter kernel
-        interp = self.interpret
         with_sup = iceberg
 
         def make(impl):
@@ -433,7 +433,7 @@ class ClosureEngine:
                             gc, gs,
                             fkern.pack_scalars(n_valid, min_sup, 0, 0),
                             parent=parents, lowrow=LOW_c[gens],
-                            iceberg=True, cbo=True, interpret=interp,
+                            iceberg=True, cbo=True,
                         )
                         n, gc, gens = _compact(keep, gc, gens)
                         return gc, gens, n
@@ -446,7 +446,7 @@ class ClosureEngine:
                             gc, jnp.zeros(gc.shape[0], jnp.int32),
                             fkern.pack_scalars(n_valid, 0, 0, 0),
                             parent=parents, lowrow=LOW_c[gens],
-                            cbo=True, interpret=interp,
+                            cbo=True,
                         )
                         n, gc, gens = _compact(keep, gc, gens)
                         return gc, gens, n
@@ -457,7 +457,7 @@ class ClosureEngine:
                 def post(gc, gs, n_valid, min_sup):
                     _, keep = fkern.filter_call(
                         gc, gs, fkern.pack_scalars(n_valid, min_sup, 0, 0),
-                        iceberg=True, interpret=interp,
+                        iceberg=True,
                     )
                     return compact_out(keep, gc)
 
@@ -555,7 +555,6 @@ class ClosureEngine:
                 )
             )
 
-        interp = self.interpret
         with_sup = iceberg
 
         def make(impl):
@@ -582,7 +581,7 @@ class ClosureEngine:
                         )
                         _, keep = fkern.filter_call(
                             gc, gs, sc, parent=parents, lowrow=LOW_c[gens],
-                            iceberg=True, cbo=True, interpret=interp,
+                            iceberg=True, cbo=True,
                         )
                         n, gc, gens = _compact(keep, gc, gens)
                         return gc, gens, n
@@ -595,7 +594,7 @@ class ClosureEngine:
                         _, keep = fkern.filter_call(
                             gc, jnp.zeros(gc.shape[0], jnp.int32), sc,
                             parent=parents, lowrow=LOW_c[gens],
-                            cbo=True, interpret=interp,
+                            cbo=True,
                         )
                         n, gc, gens = _compact(keep, gc, gens)
                         return gc, gens, n
@@ -615,7 +614,7 @@ class ClosureEngine:
                         n_valid, min_sup, 0, idx * gc.shape[0]
                     )
                     _, keep = fkern.filter_call(
-                        gc, gs, sc, iceberg=True, interpret=interp
+                        gc, gs, sc, iceberg=True
                     )
                     return compact_out(keep, gc)
 

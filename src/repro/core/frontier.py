@@ -57,6 +57,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import lectic
 from repro.kernels import frontier as fkern
@@ -69,14 +70,41 @@ from repro.obs import trace as obs
 # ---------------------------------------------------------------------------
 
 
+def _partition(valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(count, perm)``: the stable permutation moving rows with ``valid``
+    to the front — ``argsort(~valid)``, built from prefix sums and one
+    scatter instead of a sort (XLA's TPU sort takes seconds to compile
+    per shape, and the drivers compile one per bucket)."""
+    n = valid.sum(dtype=jnp.int32)
+    dest = jnp.where(
+        valid,
+        jnp.cumsum(valid, dtype=jnp.int32) - 1,
+        n + jnp.cumsum(~valid, dtype=jnp.int32) - 1,
+    )
+    idx = jnp.arange(valid.shape[0], dtype=jnp.int32)
+    return n, jnp.zeros_like(idx).at[dest].set(idx, unique_indices=True)
+
+
 def _compact(valid: jax.Array, *arrays) -> tuple:
     """Stable-move rows with ``valid`` to the front of every array.
 
     Returns ``(count, *reordered_arrays)`` — shapes unchanged (rows past
     ``count`` are garbage the caller slices away after a scalar sync).
     """
-    perm = jnp.argsort(~valid)  # jax argsort is stable
-    return (valid.sum(dtype=jnp.int32), *(a[perm] for a in arrays))
+    n, perm = _partition(valid)
+    return (n, *(a[perm] for a in arrays))
+
+
+def _lexsort_rows(seeds: jax.Array, valid: jax.Array) -> jax.Array:
+    """``jnp.lexsort`` order of packed rows, invalid rows last: one stable
+    single-key sort per word, least significant first, then the validity
+    partition.  The same permutation as one sort keyed on every word, but
+    XLA compiles it for a TPU in a fraction of the time (a 128k-row
+    six-operand sort takes minutes)."""
+    perm = jnp.arange(seeds.shape[0], dtype=jnp.int32)
+    for w in reversed(range(seeds.shape[1])):
+        _, perm = lax.sort((seeds[perm, w], perm), num_keys=1, is_stable=True)
+    return perm[_partition(valid[perm])[1]]
 
 
 def _sort_unique(seeds: jax.Array, valid: jax.Array, *arrays) -> tuple:
@@ -86,8 +114,7 @@ def _sort_unique(seeds: jax.Array, valid: jax.Array, *arrays) -> tuple:
     ever compares real rows.  Returns ``(count, seeds, *arrays)`` with the
     unique valid rows moved to the front.
     """
-    keys = tuple(seeds[:, w] for w in reversed(range(seeds.shape[1]))) + (~valid,)
-    perm = jnp.lexsort(keys)
+    perm = _lexsort_rows(seeds, valid)
     seeds = seeds[perm]
     valid = valid[perm]
     same_prev = jnp.all(seeds == jnp.roll(seeds, 1, axis=0), axis=-1)
@@ -485,6 +512,9 @@ class DeviceFrontier:
                     # driver's filter.  Values are zero-arg builders; built
                     # steps land in "steps".
                     "steps": {},
+                    # step names routed to the fused Pallas kernels below;
+                    # each dispatch of one counts in stats.fused_steps
+                    "fused": frozenset(),
                     "builders": {
                         "plain": lambda: engine.spmd_step(),
                         "unique": lambda: engine.spmd_step(
@@ -562,6 +592,7 @@ class DeviceFrontier:
                             )
                         )
                     cache["builders"].update(fused)
+                    cache["fused"] = frozenset(fused)
                 engine._frontier_cache = cache
         self._cache = cache
         self.LOW = cache["LOW"]
@@ -575,6 +606,8 @@ class DeviceFrontier:
         dict is shared by every frontier of the engine, including ones
         driven from the admission dispatcher thread, and a concurrent
         first-miss must not build (and jit) the same step twice."""
+        if name in self._cache["fused"]:
+            self.engine.stats.fused_steps += 1
         steps = self._cache["steps"]
         fn = steps.get(name)
         if fn is None:

@@ -38,17 +38,6 @@ def _and_fold(x: jax.Array) -> jax.Array:
     return x[0]
 
 
-def _axis_size(axis_names) -> int:
-    from jax import core as jax_core
-
-    names = (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
-    k = 1
-    for a in names:
-        frame = jax_core.axis_frame(a)
-        k *= frame if isinstance(frame, int) else frame.size
-    return k
-
-
 def and_allreduce(
     x: jax.Array,
     axis_names,
@@ -64,7 +53,7 @@ def and_allreduce(
     """
     if impl not in IMPLS:
         raise ValueError(f"unknown reduce impl {impl!r}; choose {IMPLS}")
-    k = _axis_size(axis_names)
+    k = lax.axis_size(axis_names)
     if k == 1:
         return x
 

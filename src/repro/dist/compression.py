@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 _LEVELS = 127.0
 
@@ -62,7 +61,7 @@ def make_ddp_step(value_and_grad_fn, mesh, *, lr: float, axis_name: str = "data"
         )
         return new_params, new_err, lax.psum(loss, axis_name) / k
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(axis_name), P(axis_name)),

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(stage_fn, stage_weights, x, mesh, *, axis_name: str = "model"):
     """Apply ``n_stages`` chained stages to microbatched input.
@@ -54,7 +52,7 @@ def pipeline_apply(stage_fn, stage_weights, x, mesh, *, axis_name: str = "model"
         out = jnp.where(s == n_stages - 1, out, 0)
         return lax.psum(out, axis_name)
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P()),

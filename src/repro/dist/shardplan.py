@@ -52,7 +52,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.dist import collectives
 from repro.dist.partition import object_axes
 
@@ -406,7 +405,7 @@ class ShardPlan:
                     P(self.axis_names) if s else P() for s in out_shard
                 )
             return _attach_audit(
-                compat.shard_map(
+                jax.shard_map(
                     fused,
                     mesh=self.mesh,
                     in_specs=in_specs,
@@ -544,7 +543,7 @@ class ShardPlan:
                     + cand_specs
                     + (P(),) * (len(ops) - n_cand)
                 )
-                return compat.shard_map(
+                return jax.shard_map(
                     fused,
                     mesh=self.mesh,
                     in_specs=in_specs,

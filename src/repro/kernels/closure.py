@@ -35,7 +35,8 @@ Padding discipline (enforced by ``ops.py``):
 
 dtype note: the kernel operates on uint32 words; on TPU Mosaic these lower
 as 32-bit integer lanes (bitwise ops are dtype-width agnostic).  The kernel
-is validated in ``interpret=True`` mode against ``ref.py`` on CPU.
+is compiled on TPU and interpreted on CPU (:mod:`repro.kernels.mosaic`),
+where it is validated against ``ref.py``.
 """
 
 from __future__ import annotations
@@ -44,10 +45,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
-
-from repro import compat
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mosaic import pallas_call
 
 DEFAULT_B_BLK = 8
 DEFAULT_N_BLK = 256
@@ -55,16 +57,32 @@ MAX_W = 512
 FULL_WORD = 0xFFFFFFFF  # python int — becomes an in-kernel literal
 
 
-def _tree_and(x: jax.Array, axis: int) -> jax.Array:
-    """Bitwise-AND reduce along ``axis`` via a log2 tree (static shapes)."""
-    x = jnp.moveaxis(x, axis, 0)
-    n = x.shape[0]
+def tree_reduce(x: jax.Array, axis: int, op) -> jax.Array:
+    """Reduce ``axis`` with the associative, commutative ``op`` via a log2
+    tree (static shapes).
+
+    Each level combines the two contiguous halves: strided pairs
+    (``x[0::2]``) lower to gathers Mosaic refuses, and an empty tail slice
+    is a zero-size vector it refuses too, so the odd element (if any) is
+    carried by a one-row slice.  Any tree order gives the same bits.
+    """
+    n = x.shape[axis]
     while n > 1:
         half = n // 2
-        paired = x[: 2 * half]
-        x = jnp.concatenate([paired[0::2] & paired[1::2], x[2 * half :]], axis=0)
-        n = x.shape[0]
-    return x[0]
+        y = op(
+            lax.slice_in_dim(x, 0, half, axis=axis),
+            lax.slice_in_dim(x, half, 2 * half, axis=axis),
+        )
+        if n % 2:
+            tail = lax.slice_in_dim(x, 2 * half, n, axis=axis)
+            y = jnp.concatenate([y, tail], axis=axis)
+        x, n = y, y.shape[axis]
+    return lax.index_in_dim(x, 0, axis, keepdims=False)
+
+
+def _tree_and(x: jax.Array, axis: int) -> jax.Array:
+    """Bitwise-AND reduce along ``axis``."""
+    return tree_reduce(x, axis, jnp.bitwise_and)
 
 
 def _closure_kernel(cand_ref, rows_ref, out_c_ref, out_s_ref):
@@ -93,16 +111,13 @@ def _closure_kernel(cand_ref, rows_ref, out_c_ref, out_s_ref):
         out_s_ref[...] = out_s_ref[...] + sup
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_b", "block_n", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_b", "block_n"))
 def closure_pallas(
     rows: jax.Array,
     cands: jax.Array,
     *,
     block_b: int = DEFAULT_B_BLK,
     block_n: int = DEFAULT_N_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw kernel invocation.  Shapes must already be block-aligned.
 
@@ -120,7 +135,7 @@ def closure_pallas(
         raise ValueError(f"unaligned shapes N={N}%{block_n}, B={B}%{block_b}")
 
     grid = (B // block_b, N // block_n)
-    out_c, out_s = pl.pallas_call(
+    out_c, out_s = pallas_call(
         _closure_kernel,
         grid=grid,
         in_specs=[
@@ -135,9 +150,8 @@ def closure_pallas(
             jax.ShapeDtypeStruct((B, W), jnp.uint32),
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=interpret,
     )(cands, rows)
     return out_c, out_s[:, 0]

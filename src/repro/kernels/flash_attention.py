@@ -22,8 +22,8 @@ matching backward kernel (dq/dk/dv with recomputed probabilities) — left
 as the documented next step; the pure-jnp `blockwise_attention` remains
 the differentiable path.
 
-Validated in interpret mode against a plain-softmax oracle (`ref.py`) over
-shape/window/softcap sweeps.
+Validated, interpreted on CPU (:mod:`repro.kernels.mosaic`), against a
+plain-softmax oracle (`ref.py`) over shape/window/softcap sweeps.
 """
 
 from __future__ import annotations
@@ -34,9 +34,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro import compat
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mosaic import pallas_call
 
 NEG_INF = -2.0**30
 DEFAULT_Q_BLK = 128
@@ -103,7 +103,7 @@ def _flash_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "window", "logit_cap", "q_blk", "kv_blk", "interpret",
+        "causal", "window", "logit_cap", "q_blk", "kv_blk",
     ),
 )
 def flash_attention(
@@ -116,7 +116,6 @@ def flash_attention(
     logit_cap: float | None = None,
     q_blk: int = DEFAULT_Q_BLK,
     kv_blk: int = DEFAULT_KV_BLK,
-    interpret: bool = True,
 ) -> jax.Array:
     """Returns [B, H, S, hd].  S/T padded internally to block multiples."""
     B, H, S, hd = q.shape
@@ -144,7 +143,7 @@ def flash_attention(
         scale=scale, causal=causal, window=window, logit_cap=logit_cap,
         kv_blk=kv_blk, q_blk=q_blk, seq_len=T,
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -166,9 +165,8 @@ def flash_attention(
             pltpu.VMEM((q_blk, 1), jnp.float32),
             pltpu.VMEM((q_blk, hd), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
     )(qf, k, v)
     return out.reshape(B, H, Sp, hd)[:, :, :S, :]

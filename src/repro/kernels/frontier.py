@@ -33,7 +33,7 @@ survivor mask:
     globally reduced ``[B, W]`` block — the three driver-filter ops fused
     into a single VMEM pass.
 
-The driver-side compaction (``_compact`` / ``_sort_unique`` argsorts in
+The driver-side compaction (``_compact`` / ``_sort_unique`` in
 :mod:`repro.core.frontier`) stays jnp: a data-dependent permutation is
 XLA's job, and it consumes only the kernel's survivor mask + closures —
 never a full intermediate.  CbO's canonicity operand ``LOW[gen]`` is
@@ -44,7 +44,8 @@ Padding discipline matches ``kernels/closure.py``: context rows padded to
 ``block_n`` multiples with all-ones AND-identity rows (supports corrected
 in-kernel via the scalar operand), candidate caps are power-of-two buckets
 ``≥ block_b``.  Everything is validated bit-identical to the jnp step
-oracles in interpret mode (tests/test_fused_frontier.py); widths beyond
+oracles with the kernels interpreted on CPU (tests/test_fused_frontier.py)
+and compiled for v5e (tests/test_tpu_compile.py); widths beyond
 ``MAX_W`` take the jnp path, same as ``ops.batched_closure``.
 """
 
@@ -56,8 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from repro import compat
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.closure import (
@@ -67,6 +66,7 @@ from repro.kernels.closure import (
     MAX_W,
     _tree_and,
 )
+from repro.kernels.mosaic import pallas_call
 
 # scalar-prefetch operand layout (int32 [4], SMEM):
 #   [0] n_valid   — valid candidate rows in the (whole-chunk) batch
@@ -168,7 +168,7 @@ def _fused_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("iceberg", "cbo", "block_b", "block_n", "interpret"),
+    static_argnames=("iceberg", "cbo", "block_b", "block_n"),
 )
 def fused_closure_call(
     rows: jax.Array,
@@ -182,7 +182,6 @@ def fused_closure_call(
     cbo: bool = False,
     block_b: int = DEFAULT_B_BLK,
     block_n: int = DEFAULT_N_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The fully fused frontier step (single-object-shard plans).
 
@@ -223,7 +222,7 @@ def fused_closure_call(
             pl.BlockSpec((block_b, 1), lambda b, n, s: (b, 0)),
         ],
     )
-    out_c, out_s, out_k = pl.pallas_call(
+    out_c, out_s, out_k = pallas_call(
         functools.partial(_fused_kernel, iceberg, cbo),
         grid_spec=grid_spec,
         out_shape=[
@@ -231,10 +230,9 @@ def fused_closure_call(
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=interpret,
     )(scalars, *inputs)
     return out_c, out_s[:, 0], out_k[:, 0] > 0
 
@@ -268,7 +266,7 @@ def _map_kernel(s_ref, cand_ref, rows_ref, mask_ref, out_c_ref, out_s_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_b", "block_n", "interpret")
+    jax.jit, static_argnames=("block_b", "block_n")
 )
 def map_closure_call(
     rows: jax.Array,
@@ -277,7 +275,6 @@ def map_closure_call(
     *,
     block_b: int = DEFAULT_B_BLK,
     block_n: int = DEFAULT_N_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-shard map half for multi-shard plans: masked local closures
     [B, W] + raw local supports [B] (pad correction happens after the
@@ -301,17 +298,16 @@ def map_closure_call(
             pl.BlockSpec((block_b, 1), lambda b, n, s: (b, 0)),
         ],
     )
-    out_c, out_s = pl.pallas_call(
+    out_c, out_s = pallas_call(
         _map_kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, W), jnp.uint32),
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=interpret,
     )(jnp.zeros((N_SCALARS,), jnp.int32), cands, rows, mask)
     return out_c, out_s[:, 0]
 
@@ -336,7 +332,7 @@ def _filter_kernel(iceberg, cbo, s_ref, gc_ref, gs_ref, *refs):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("iceberg", "cbo", "block_b", "interpret"),
+    static_argnames=("iceberg", "cbo", "block_b"),
 )
 def filter_call(
     gc: jax.Array,
@@ -348,7 +344,6 @@ def filter_call(
     iceberg: bool = False,
     cbo: bool = False,
     block_b: int = DEFAULT_B_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Post-reduce fused driver filter for multi-shard plans.
 
@@ -380,17 +375,16 @@ def filter_call(
             pl.BlockSpec((block_b, 1), lambda b, s: (b, 0)),
         ],
     )
-    out_s, out_k = pl.pallas_call(
+    out_s, out_k = pallas_call(
         functools.partial(_filter_kernel, iceberg, cbo),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
-        interpret=interpret,
     )(scalars, *inputs)
     return out_s[:, 0], out_k[:, 0] > 0
 

@@ -41,7 +41,6 @@ def _attr_mask_jnp(n_attrs: int, W: int) -> jnp.ndarray:
         "block_b",
         "block_n",
         "use_kernel",
-        "interpret",
         "fused_reduce",
     ),
 )
@@ -54,7 +53,6 @@ def batched_closure(
     block_b: int = 8,
     block_n: int = 256,
     use_kernel: bool = True,
-    interpret: bool = True,
     fused_reduce: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched closure with clean semantics.  rows [N,W], cands [B,W]."""
@@ -81,7 +79,7 @@ def batched_closure(
         )
 
     closures, supports = closure_pallas(
-        rows, cands, block_b=block_b, block_n=block_n, interpret=interpret
+        rows, cands, block_b=block_b, block_n=block_n
     )
     closures = closures[:B] & mask
     # All-ones padding rows (pre-existing + internal) match every candidate.

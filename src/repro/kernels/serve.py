@@ -19,11 +19,12 @@ resident from the subset test to the packed top-k result.
     tie-break.
 
 Both mirror the jnp steps in :mod:`repro.query.engine` bit-for-bit (the
-unrolled argmax/max passes use the identical mask-and-repeat recurrence;
-the in-kernel ``where(iota == pos)`` scatter equals ``.at[rows, pos].set``
-because ``pos`` is unique per row).  Oversized tables fall back to the jnp
-step — see :func:`supports_serve`.  Interpret-mode equivalence is asserted
-in tests/test_fused_frontier.py.
+unrolled max passes use the identical mask-and-repeat recurrence, with
+the lowest index winning ties as argmax does; the in-kernel
+``where(iota == pos)`` scatter equals ``.at[rows, pos].set`` because
+``pos`` is unique per row).  Tables past the VMEM bound fall back to the
+jnp step — see :func:`supports_serve`.  Equivalence, interpreted on CPU,
+is asserted in tests/test_fused_frontier.py.
 """
 
 from __future__ import annotations
@@ -34,34 +35,65 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from repro import compat
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.closure import MAX_W
+from repro.kernels.closure import MAX_W, tree_reduce
+from repro.kernels.mosaic import pallas_call
 
 # Queries per grid step (the slot axis is blocked; tables ride whole).
 DEFAULT_S_BLK = 8
 
-# Table-size ceiling for the VMEM-resident path: rows × words of the
-# replicated table a single grid step holds.  ~16 MiB of uint32 at the
-# cap — beyond it the jnp step is the right tool (its score matrix tiles
-# naturally under XLA), so callers fall back rather than thrash VMEM.
-MAX_TABLE_CELLS = 1 << 22
+# Scoped VMEM one grid step may use: v5e's default scoped-VMEM limit.
+# Mosaic refuses a kernel above it at compile time (RESOURCE_EXHAUSTED).
+VMEM_LIMIT_BYTES = 16 << 20
+
+# Whole-table operands per kernel: ([rows, W] tables, (1, rows) vectors).
+_TABLE_OPERANDS = {"topk": (1, 1), "rules": (2, 3)}
 
 
-def supports_serve(backend: str, n_rows: int, W: int, slots: int) -> bool:
-    """Whether the fused serving kernels can serve this table/batch shape."""
+def serve_vmem_bytes(
+    kind: str, n_rows: int, W: int, block_s: int = DEFAULT_S_BLK
+) -> int:
+    """VMEM bytes one grid step of the ``kind`` kernel ("topk" or "rules")
+    needs for an ``n_rows × W``-word table.
+
+    Every ``[.., W]`` uint32 array pads W to whole 128-lane vregs and every
+    ``(1, rows)`` vector to 8 sublanes.  The ``[block_s, rows, W]`` subset
+    test dominates: Mosaic keeps two copies of it live.  The whole tables
+    and row vectors ride beside it.  The v5e compile rehearsal
+    (tests/test_tpu_compile.py) checks the bound on both sides.
+    """
+    tables, vectors = _TABLE_OPERANDS[kind]
+    row_bytes = -(-W // 128) * 128 * 4
+    subset = 2 * block_s * n_rows * row_bytes
+    table = tables * n_rows * row_bytes
+    vecs = vectors * 8 * (-(-n_rows // 128) * 128) * 4
+    return subset + table + vecs
+
+
+def supports_serve(
+    backend: str, kind: str, n_rows: int, W: int, slots: int
+) -> bool:
+    """Whether the fused ``kind`` serving kernel can serve this table and
+    batch shape; past the VMEM bound the jnp step serves instead (tiling
+    the row axis would lift the bound)."""
     return (
         backend == "kernel"
         and W <= MAX_W
-        and n_rows * max(W, 1) <= MAX_TABLE_CELLS
+        and serve_vmem_bytes(kind, n_rows, W) <= VMEM_LIMIT_BYTES
         and slots % DEFAULT_S_BLK == 0
     )
 
 
+def _first_index(hit, col, n):
+    """Lowest column where ``hit`` holds, per row ([S, n] → [S]) — the
+    argmax of a bool/int row, written as a min over ``where(hit, iota, n)``
+    because Mosaic lowers argmax for float32 only."""
+    return jnp.min(jnp.where(hit, col, jnp.int32(n)), axis=1)
+
+
 def _topk_int(scores, k):
-    """k unrolled argmax passes over int scores [S, C] → (idx, vals).
+    """k unrolled max passes over int scores [S, C] → (idx, vals).
 
     Same order as lax.top_k (desc value, asc index on ties); the repeat
     recurrence masks the taken cell with -2 < every live score ≥ -1.
@@ -70,8 +102,8 @@ def _topk_int(scores, k):
     col = lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     ids, vals = [], []
     for _ in range(k):
-        idx = jnp.argmax(scores, axis=1).astype(jnp.int32)
-        val = jnp.max(scores, axis=1)  # == scores[row, argmax] by definition
+        val = jnp.max(scores, axis=1)
+        idx = _first_index(scores == val[:, None], col, C)
         ids.append(idx)
         vals.append(val)
         scores = jnp.where(col == idx[:, None], jnp.int32(-2), scores)
@@ -95,7 +127,7 @@ def _contains_topk_kernel(k, s_ref, gc_ref, int_ref, sup_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "block_s", "interpret")
+    jax.jit, static_argnames=("k", "block_s")
 )
 def contains_topk_call(
     gc: jax.Array,
@@ -105,7 +137,6 @@ def contains_topk_call(
     *,
     k: int,
     block_s: int = DEFAULT_S_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused top-k-by-support over concepts containing each closed query.
 
@@ -131,17 +162,16 @@ def contains_topk_call(
             pl.BlockSpec((block_s, k), lambda b, s: (b, 0)),
         ],
     )
-    out_i, out_v = pl.pallas_call(
+    out_i, out_v = pallas_call(
         functools.partial(_contains_topk_kernel, k),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((S, k), jnp.int32),
             jax.ShapeDtypeStruct((S, k), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
-        interpret=interpret,
     )(
         jnp.asarray(n_concepts, jnp.int32)[None],
         gc,
@@ -152,15 +182,8 @@ def contains_topk_call(
 
 
 def _tree_or(x: jax.Array, axis: int) -> jax.Array:
-    """Bitwise-OR reduce along ``axis`` via a log2 tree (static shapes)."""
-    x = jnp.moveaxis(x, axis, 0)
-    n = x.shape[0]
-    while n > 1:
-        half = n // 2
-        paired = x[: 2 * half]
-        x = jnp.concatenate([paired[0::2] | paired[1::2], x[2 * half :]], axis=0)
-        n = x.shape[0]
-    return x[0]
+    """Bitwise-OR reduce along ``axis``."""
+    return tree_reduce(x, axis, jnp.bitwise_or)
 
 
 def _rules_topk_kernel(k, s_ref, q_ref, prem_ref, add_ref, conf_ref,
@@ -189,7 +212,7 @@ def _rules_topk_kernel(k, s_ref, q_ref, prem_ref, add_ref, conf_ref,
         sel = jnp.min(
             jnp.where(is_best, rid, jnp.int32(0x7FFFFFFF)), axis=1
         )
-        pos = jnp.argmax(is_best & (rid == sel[:, None]), axis=1)
+        pos = _first_index(is_best & (rid == sel[:, None]), col, R)
         ids.append(sel)
         vals.append(best)
         score = jnp.where(col == pos[:, None], jnp.float32(-2.0), score)
@@ -200,7 +223,7 @@ def _rules_topk_kernel(k, s_ref, q_ref, prem_ref, add_ref, conf_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "block_s", "interpret")
+    jax.jit, static_argnames=("k", "block_s")
 )
 def rules_topk_call(
     prem: jax.Array,
@@ -214,7 +237,6 @@ def rules_topk_call(
     *,
     k: int,
     block_s: int = DEFAULT_S_BLK,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused rule lookup: premise ⊆ query → conf/validity mask → consequent
     union → metric top-k with rule-id tie-break, one pass per query block.
@@ -247,7 +269,7 @@ def rules_topk_call(
             pl.BlockSpec((block_s, W), lambda b, s: (b, 0)),
         ],
     )
-    out_i, out_v, out_u = pl.pallas_call(
+    out_i, out_v, out_u = pallas_call(
         functools.partial(_rules_topk_kernel, k),
         grid_spec=grid_spec,
         out_shape=[
@@ -255,10 +277,9 @@ def rules_topk_call(
             jax.ShapeDtypeStruct((S, k), jnp.float32),
             jax.ShapeDtypeStruct((S, W), jnp.uint32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
-        interpret=interpret,
     )(
         jnp.asarray(n_rules, jnp.int32)[None],
         queries,

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import get_config, get_plan, get_shape
 from repro.dist.partition import Partitioner
 from repro.launch import hlo_analysis
@@ -162,7 +161,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_label: str, **kw) -> dict:
         compiled = lowered.compile()
         rec["compile_s"] = round(time.perf_counter() - t1, 1)
 
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
         an = hlo_analysis.analyze(hlo)
@@ -245,7 +244,7 @@ def run_fca_cell(mesh, mesh_label: str, n_objects: int = 1 << 23,
             gs = jax.lax.psum(ls, data_axes)
             return gc & mask, gs
 
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(data_axes, None), P()), out_specs=(P(), P()),
             check_vma=False,
@@ -258,7 +257,7 @@ def run_fca_cell(mesh, mesh_label: str, n_objects: int = 1 << 23,
         compiled = lowered.compile()
         rec["compile_s"] = round(time.perf_counter() - t0, 1)
         an = hlo_analysis.analyze(compiled.as_text())
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         rec.update(
             status="ok",
             flops_per_device=float(an.flops),

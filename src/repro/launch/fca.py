@@ -47,7 +47,10 @@ stats carry HDR-histogram p50/p95/p99 micro-batch latencies
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
+import os
+import pathlib
 import sys
 import time
 
@@ -66,6 +69,32 @@ from repro.obs import (
     stop_device_trace,
     use_tracer,
 )
+
+
+# Where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+# is unset: one fixed path inside the checkout (listed in .gitignore), so
+# every run from this checkout finds what an earlier run compiled.
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compile cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    nothing is set here; otherwise the cache goes to :data:`CACHE_DIR`.
+    """
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return jax.config.jax_compilation_cache_dir
+
+
+def concepts_digest(intents) -> str:
+    """Order-free fingerprint of a concept set (its packed intents): two
+    runs found the same concepts iff their digests match."""
+    rows = np.unique(np.asarray(intents, np.uint32), axis=0)
+    return hashlib.sha256(rows.tobytes()).hexdigest()[:16]
 
 
 def build_plan(args) -> ShardPlan:
@@ -126,8 +155,10 @@ def cmd_mine(args, ctx, spec, plan, backend):
         "algorithm": res.algorithm,
         "min_support_resolved": res.min_support,
         "concepts": res.n_concepts,
+        "concepts_digest": concepts_digest(res.intents),
         "iterations": res.n_iterations,
         "closures_computed": res.n_closures_computed,
+        "fused_steps": eng.stats.fused_steps,
         "modeled_comm_bytes": res.modeled_comm_bytes,
         "modeled_dispatch_bytes": eng.stats.modeled_dispatch_bytes,
         "modeled_collective_bytes": eng.stats.modeled_collective_bytes,
@@ -203,6 +234,9 @@ def cmd_serve(args, ctx, spec, plan, backend):
         "algorithm": res.algorithm,
         "min_support_resolved": res.min_support,
         "concepts": res.n_concepts,
+        "concepts_digest": concepts_digest(res.intents),
+        "mine_iterations": res.n_iterations,
+        "mine_fused_steps": eng.stats.fused_steps,
         "mine_wall_s": round(res.wall_time_s, 3),
         "store": store.describe(),
         "store_build_s": round(build_s, 3),
@@ -370,7 +404,9 @@ def cmd_rules(args, ctx, spec, plan, backend):
         "min_support_resolved": min_support,
         "min_conf": args.min_conf,
         "iceberg_concepts": res.n_concepts,
+        "concepts_digest": concepts_digest(res.intents),
         "mine_iterations": res.n_iterations,
+        "mine_fused_steps": eng.stats.fused_steps,
         "mine_wall_s": round(res.wall_time_s, 3),
         "store_build_s": round(build_s, 3),
         "basis": basis.describe(),
@@ -398,7 +434,7 @@ def dataclass_dict(obj):
     return dataclasses.asdict(obj)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser()
     p.add_argument("command", nargs="?", default="mine",
                    choices=["mine", "serve", "rules"],
@@ -525,8 +561,11 @@ def main(argv=None):
     p.add_argument("--device-trace", metavar="DIR", default=None,
                    help="pass-through to jax.profiler.start_trace(DIR): "
                         "capture the XLA device timeline alongside --trace")
-    args = p.parse_args(argv)
+    return p.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> dict:
+    """Run one ``fca`` subcommand in this process; return its JSON stats."""
     backend = args.backend
     if backend is None:
         backend = "jnp" if args.no_kernel else "kernel"
@@ -559,7 +598,12 @@ def main(argv=None):
     if args.stats_json:
         with open(args.stats_json, "w") as fh:
             json.dump(out, fh, indent=2)
-    print(json.dumps(out, indent=2))
+    return out
+
+
+def main(argv=None):
+    enable_compile_cache()
+    print(json.dumps(run(parse_args(argv)), indent=2))
 
 
 if __name__ == "__main__":

@@ -10,14 +10,12 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_local_mesh(
@@ -36,7 +34,7 @@ def make_local_mesh(
     if pod > 1:
         dims.append(("pod", pod))
     dims += [("data", data), ("model", model)]
-    return compat.make_mesh(
+    return jax.make_mesh(
         tuple(s for _, s in dims), tuple(a for a, _ in dims)
     )
 

@@ -88,8 +88,7 @@ def _moe_ep_shardmap(params, xf, top_w, top_i, cfg: ModelConfig, shard, exact: b
         y_r = jnp.zeros((N_loc, d), xf_l.dtype).at[tok_s].add(contrib)
         return lax.psum(y_r, "model")
 
-    from repro import compat
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -33,8 +33,8 @@ Optional device-side correlation: ``Tracer(jax_annotations=True)`` enters
 a ``jax.profiler.TraceAnnotation`` for every span so host spans line up
 with XLA's own profiler timeline, and :func:`start_device_trace` /
 :func:`stop_device_trace` pass through ``jax.profiler.start_trace`` for a
-full device trace alongside the host one (both best-effort: missing
-profiler support degrades to host-only tracing, never an error).
+full device trace alongside the host one.  A profiler that fails raises:
+a run asked for a device trace never ends without one in silence.
 
 ``python -m repro.obs.trace out.json`` validates a saved trace (schema +
 span well-formedness; ``--expect-async-overlap`` additionally requires a
@@ -313,31 +313,23 @@ def use_tracer(tracer):
 
 
 # ---------------------------------------------------------------------------
-# device-trace pass-through (optional, best-effort)
+# device-trace pass-through
 # ---------------------------------------------------------------------------
 
 
-def start_device_trace(log_dir: str) -> bool:
-    """Begin a jax.profiler device trace alongside the host tracer.
-    Returns False (instead of raising) when the runtime has no profiler
-    support — host tracing keeps working either way."""
-    try:  # pragma: no cover — depends on runtime profiler support
-        import jax
+def start_device_trace(log_dir: str) -> None:
+    """Begin a jax.profiler device trace into ``log_dir`` alongside the
+    host tracer; raises if the profiler cannot start."""
+    import jax
 
-        jax.profiler.start_trace(log_dir)
-        return True
-    except Exception:
-        return False
+    jax.profiler.start_trace(log_dir)
 
 
-def stop_device_trace() -> bool:
-    try:  # pragma: no cover
-        import jax
+def stop_device_trace() -> None:
+    """End the device trace and write it; raises if the profiler fails."""
+    import jax
 
-        jax.profiler.stop_trace()
-        return True
-    except Exception:
-        return False
+    jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -63,11 +63,18 @@ class QueryStats(StatsBase):
     collective_rounds: int = 0
     modeled_comm_bytes: int = 0
     by_type: dict = dataclasses.field(default_factory=dict)
+    # micro-batches per "kind/path": which step served each top-k and
+    # rules batch — the fused Pallas kernel or the jnp step
+    serve_paths: dict = dataclasses.field(default_factory=dict)
 
     def charge(self, kind: str, n: int, batches: int):
         self.queries += n
         self.micro_batches += batches
         self.by_type[kind] = self.by_type.get(kind, 0) + n
+
+    def count_path(self, kind: str, kernel: bool, batches: int):
+        key = f"{kind}/{'kernel' if kernel else 'jnp'}"
+        self.serve_paths[key] = self.serve_paths.get(key, 0) + batches
 
 
 @dataclasses.dataclass
@@ -75,7 +82,6 @@ class QueryConfig:
     slots: int = 64  # fixed micro-batch width; every dispatch pads to this
     backend: str = "jnp"  # closure map backend, as in ClosureEngine
     block_n: int = 256
-    interpret: bool = True
 
 
 class QueryEngine:
@@ -130,7 +136,6 @@ class QueryEngine:
             n_valid_rows=rows_local.shape[0],
             block_n=cfg.block_n,
             use_kernel=cfg.backend == "kernel",
-            interpret=cfg.interpret,
         )
 
     def _closure_body(self, impl: str):
@@ -179,22 +184,17 @@ class QueryEngine:
             step = self._topk_steps.get((impl, k))
             if step is not None:
                 return step
-            cfg = self.cfg
 
             def post(gc, gs, intents, supports, n_concepts):
                 # backend="kernel": the whole post — subset test, validity
                 # mask, k selection passes — runs as ONE fused Pallas pass
                 # with the query block and intent table VMEM-resident
                 # (repro.kernels.serve).  Bit-identical to the jnp stage
-                # below, which remains its tested oracle; oversized tables
-                # fall back (the shapes are static at trace time).
-                if skern.supports_serve(
-                    cfg.backend, intents.shape[0], intents.shape[1],
-                    gc.shape[0],
-                ):
+                # below, which remains its tested oracle; tables past the
+                # VMEM bound fall back (the shapes are static at trace time).
+                if self._serve_kernel("topk", intents.shape[0]):
                     idx, vals = skern.contains_topk_call(
-                        gc, intents, supports, n_concepts,
-                        k=k, interpret=cfg.interpret,
+                        gc, intents, supports, n_concepts, k=k
                     )
                     return gc, gs, idx, vals
                 # concepts whose intent ⊇ the query attrset == subconcepts
@@ -260,6 +260,13 @@ class QueryEngine:
                 self.plan.spmd(body, n_rep=1, post=post)
             )
         return step
+
+    def _serve_kernel(self, kind: str, n_rows: int) -> bool:
+        """Whether the fused ``kind`` serving kernel ("topk"/"rules")
+        serves a table of ``n_rows``; the jnp step serves otherwise."""
+        return skern.supports_serve(
+            self.cfg.backend, kind, n_rows, self.W, self.cfg.slots
+        )
 
     # -- micro-batch plumbing ----------------------------------------------
 
@@ -367,6 +374,9 @@ class QueryEngine:
             self._obs_batch("topk", self.clock() - t0, snap.version)
             batches += 1
         self.stats.charge("topk", B, batches)
+        self.stats.count_path(
+            "topk", self._serve_kernel("topk", snap.intents.shape[0]), batches
+        )
         return out_i, out_v
 
     def lookup_batch(self, intents: np.ndarray) -> np.ndarray:
@@ -495,20 +505,16 @@ class QueryEngine:
             step = self._rules_steps.get(k)
             if step is not None:
                 return step
-            cfg = self.cfg
 
             def run(prem, added, conf, metric, rid, n_rules, queries, min_conf):
                 # backend="kernel": premise-subset test → conf mask →
                 # consequent union → metric top-k as one fused VMEM pass
                 # (repro.kernels.serve.rules_topk_call), bit-identical to
                 # the jnp stage below (its property-tested oracle).
-                if skern.supports_serve(
-                    cfg.backend, prem.shape[0], prem.shape[1],
-                    queries.shape[0],
-                ):
+                if self._serve_kernel("rules", prem.shape[0]):
                     return skern.rules_topk_call(
                         prem, added, conf, metric, rid, n_rules,
-                        queries, min_conf, k=k, interpret=cfg.interpret,
+                        queries, min_conf, k=k,
                     )
                 R = prem.shape[0]
                 # applicable[b, r]: premise_r ⊆ query attrset b
@@ -610,6 +616,9 @@ class QueryEngine:
             self._obs_batch("rules", self.clock() - t0)
             batches += 1
         self.stats.charge("rules", B, batches)
+        self.stats.count_path(
+            "rules", self._serve_kernel("rules", index.cap), batches
+        )
         return out_i, out_s, out_c
 
     def describe(self) -> dict:

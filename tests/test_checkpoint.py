@@ -104,9 +104,7 @@ def test_elastic_restore_new_sharding(tmp_path):
 
     t = _tree()
     save_checkpoint(str(tmp_path), 5, t)
-    from repro import compat
-
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     sh = jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), t)
     restored = restore_checkpoint(str(tmp_path), 5, t, shardings=sh)
     assert restored["a"].sharding.is_equivalent_to(NamedSharding(mesh, P()), 2)

@@ -141,3 +141,30 @@ def test_property_device_equals_host(n, m, density, seed, n_parts, dedupe):
         ctx, mrganter_plus, n_parts=n_parts, dedupe_candidates=dedupe
     )
     _assert_equiv(ctx, mrcbo, n_parts=n_parts)
+
+
+@pytest.mark.parametrize(
+    "n,W,hi,p_valid",
+    [(1, 1, 2, 0.5), (7, 3, 3, 0.5), (1000, 4, 4, 0.7),
+     (5000, 5, 2**32 - 1, 0.9), (64, 2, 2, 0.0), (64, 2, 2, 1.0)],
+)
+def test_sort_free_orders_equal_jnp_sorts(n, W, hi, p_valid):
+    """The chained single-key sorts and the prefix-sum partition give the
+    exact permutations of ``jnp.lexsort`` and the stable ``argsort`` they
+    replace — ties, duplicate rows, all-valid and all-invalid batches."""
+    import jax.numpy as jnp
+
+    from repro.core.frontier import _lexsort_rows, _partition
+
+    rng = np.random.default_rng(n * W)
+    seeds = jnp.asarray(
+        rng.integers(0, hi, (n, W), dtype=np.uint64).astype(np.uint32)
+    )
+    valid = jnp.asarray(rng.random(n) < p_valid)
+    keys = tuple(seeds[:, w] for w in reversed(range(W))) + (~valid,)
+    np.testing.assert_array_equal(
+        _lexsort_rows(seeds, valid), jnp.lexsort(keys)
+    )
+    count, perm = _partition(valid)
+    assert int(count) == int(valid.sum())
+    np.testing.assert_array_equal(perm, jnp.argsort(~valid, stable=True))

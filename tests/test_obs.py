@@ -367,3 +367,12 @@ def test_extents_single_part_charges_nothing(ctx):
     qe.extents_batch(np.arange(3, dtype=np.int32))
     assert qe.stats.modeled_comm_bytes == 0
     assert qe.stats.reduce_rounds == {}
+
+
+def test_device_trace_failure_raises():
+    """A profiler that fails surfaces: ``--device-trace`` must not exit 0
+    without the trace it was asked for."""
+    from repro.obs import stop_device_trace
+
+    with pytest.raises(Exception):
+        stop_device_trace()  # no trace was started
