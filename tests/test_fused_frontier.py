@@ -8,6 +8,7 @@ an equality assertion against them, across drivers, object-shard counts
 and candidate-shard counts.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -116,6 +117,109 @@ def test_map_plus_filter_equals_fused():
     np.testing.assert_array_equal(np.asarray(loc), np.asarray(gc_f))
     np.testing.assert_array_equal(np.asarray(sup_m), np.asarray(sup_f))
     np.testing.assert_array_equal(np.asarray(keep_m), np.asarray(keep_f))
+
+
+# (N objects, W words, B candidates, variant): N below one 128-lane object
+# tile and not a multiple of it, W unrolled (≤ 16, one or two sublane
+# groups) and looped (> 16), every bucket end, and a context whose word
+# planes take two object tiles (accumulation across the object steps).
+LANE_CASES = [
+    (100, 1, 8, "plain"),
+    (100, 4, 16, "iceberg"),
+    (100, 5, 1024, "cbo"),
+    (100, 10, 8, "cbo_iceberg"),
+    (1000, 1, 16, "cbo_iceberg"),
+    (1000, 4, 1024, "plain"),
+    (1000, 5, 8, "iceberg"),
+    (1000, 10, 16, "cbo"),
+    (4097, 1, 1024, "iceberg"),
+    (4097, 4, 8, "cbo"),
+    (4097, 5, 16, "cbo_iceberg"),
+    (4097, 10, 1024, "plain"),
+    (1000, 20, 16, "cbo_iceberg"),
+    (4097, 17, 8, "plain"),
+    (20000, 64, 8, "iceberg"),
+]
+
+
+@pytest.mark.parametrize("N,W,B,variant", LANE_CASES)
+def test_fused_lane_layout_matches_oracle(N, W, B, variant):
+    """The objects-on-lanes kernel ≡ the numpy oracle: closures, supports
+    and keep flags, under an ``n_valid``/``row_off`` window whose tail
+    holds all-ones pad candidates, as the frontier pads its buckets."""
+    iceberg, cbo, _ = fkern.VARIANTS[variant]
+    rng = np.random.default_rng(N * 1000 + W * 10 + B)
+    m = 32 * W - 7 if W > 1 else 29
+    ctx = FormalContext.synthetic(N, m, 0.3, seed=N + W)
+    # subsets of object rows (about 3 attributes), so supports vary
+    pick = FormalContext.synthetic(B, m, min(1.0, 8 / m), seed=B + W).rows
+    cands = ctx.rows[rng.integers(0, N, B)] & pick
+    row_off = 8 if B > 8 else 0
+    n_valid = row_off + B - max(3, B // 4)
+    cands[n_valid - row_off:] = 0xFFFFFFFF  # the bucket's pad candidates
+    rows_p, n_pad = ctx.padded_rows(8)
+    oc, os_ = batched_closure_np(ctx.rows, cands, ctx.attr_mask())
+    min_sup = int(np.median(os_[: n_valid - row_off])) if iceberg else 0
+    want = (np.arange(B) + row_off) < n_valid
+    if iceberg:
+        want &= os_ >= min_sup
+    kw = {}
+    if cbo:
+        lowrow = FormalContext.synthetic(B, m, 0.2, seed=B + N).rows
+        kw = dict(parent=jnp.asarray(cands), lowrow=jnp.asarray(lowrow))
+        want &= (((oc ^ cands) & lowrow) == 0).all(axis=1)
+    gc, sup, keep = fkern.fused_closure_call(
+        jnp.asarray(rows_p), jnp.asarray(cands),
+        jnp.asarray(ctx.attr_mask()[None, :]),
+        fkern.pack_scalars(n_valid, min_sup, n_pad, row_off),
+        iceberg=iceberg, cbo=cbo, block_n=8, **kw,
+    )
+    np.testing.assert_array_equal(np.asarray(gc), oc)
+    np.testing.assert_array_equal(np.asarray(sup), os_)
+    np.testing.assert_array_equal(np.asarray(keep), want)
+    assert 0 < want.sum() < B  # the filter cut some and kept some
+
+
+@pytest.mark.parametrize("B,N,W", [
+    (8, 104192, 5), (8192, 104192, 5), (8192, 8192, 4), (16, 100, 1),
+    (1024, 4104, 10), (8, 20000, 64), (64, 2048, 512),
+])
+def test_lane_tiles_fit(B, N, W):
+    """Tiles divide the batch, cover the objects in 128-lane steps and keep
+    the context block and the per-lane scratch inside their budgets."""
+    bb, sg, bn = fkern._lane_tiles(B, N, W)
+    wp = -(-W // 8) * 8
+    assert B % bb == 0 and bb % sg == 0 and sg % 8 == 0
+    assert bn % fkern.LANES == 0
+    n_tiles = -(-N // bn)
+    assert (n_tiles - 1) * bn < N <= n_tiles * bn
+    assert n_tiles == 1 or wp * bn * 4 <= fkern.ROWS_BLOCK_BYTES
+    assert wp * bb * fkern.LANES * 4 <= fkern.LANE_SCRATCH_BYTES
+    if (N, W) == (20000, 64):  # LANE_CASES' multi-tile context
+        assert n_tiles > 1
+
+
+def test_pallas_call_traces_kernel_once():
+    """Both platform branches share one kernel trace: the interpreted one
+    (which runs here) computes what the kernel says."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.mosaic import pallas_call
+
+    traced = []
+
+    def kernel(x_ref, o_ref):
+        traced.append(1)
+        o_ref[...] = x_ref[...] + 1
+
+    call = pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
+        in_specs=[pl.BlockSpec((8, 128), lambda: (0, 0))],
+        out_specs=pl.BlockSpec((8, 128), lambda: (0, 0)),
+    )
+    x = jnp.arange(8 * 128, dtype=jnp.int32).reshape(8, 128)
+    np.testing.assert_array_equal(np.asarray(jax.jit(call)(x)), x + 1)
+    assert len(traced) == 1
 
 
 def test_supports_fused_gate():

@@ -74,6 +74,23 @@ def test_fused_closure_compiles(one_chip, N, W, variant):
 
 
 @pytest.mark.parametrize("N,W", MINING_SHAPES)
+@pytest.mark.parametrize("B", [8, 8192])
+def test_fused_closure_compiles_at_bucket_ends(one_chip, N, W, B):
+    """The smallest frontier bucket and the batch the mining cells run:
+    Mosaic lowers the objects-on-lanes layout and its VMEM fits at both."""
+    s = lambda shape, dt=jnp.uint32: _spec(one_chip, shape, dt)
+
+    def step(rows, cands, mask, scalars, parent, lowrow):
+        return fkern.fused_closure_call(
+            rows, cands, mask, scalars, parent=parent, lowrow=lowrow,
+            iceberg=True, cbo=True,
+        )
+
+    _compile(step, s((N, W)), s((B, W)), s((1, W)),
+             s((fkern.N_SCALARS,), jnp.int32), s((B, W)), s((B, W)))
+
+
+@pytest.mark.parametrize("N,W", MINING_SHAPES)
 def test_map_closure_compiles_under_simulated_vmap(one_chip, N, W):
     parts = 8  # the simulated plan's named-axis vmap over object shards
     local = N // parts // fkern.DEFAULT_N_BLK * fkern.DEFAULT_N_BLK
