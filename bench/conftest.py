@@ -36,6 +36,10 @@ TINY_TRAFFIC = {
         "kind": "mine", "algorithm": "mrganter+", "local_prune": True,
         "parts": 2, "min_support": 0.01, "backend": "jnp", "rounds": "sync",
     },
+    "tiny_mine_4x1": {
+        "kind": "mine", "algorithm": "mrganter+", "local_prune": True,
+        "parts": 4, "min_support": 0.01, "backend": "kernel", "rounds": "sync",
+    },
     "tiny_serve": {
         "kind": "serve", "store": {"algorithm": "mrcbo", "min_support": 0.1},
         "mix": {"closure": 0.6, "topk": 0.3, "lookup": 0.1},
@@ -53,7 +57,9 @@ def load_run_module():
     return module
 
 
-def add_cell(root: pathlib.Path, name: str, config: str, traffic: str) -> None:
+def add_cell(
+    root: pathlib.Path, name: str, config: str, traffic: str, chips: int = 1
+) -> None:
     """Add a cell to the copy's BENCHMARK.json, listed by every metric
     that lists the cells of its kind."""
     path = root / "BENCHMARK.json"
@@ -61,7 +67,7 @@ def add_cell(root: pathlib.Path, name: str, config: str, traffic: str) -> None:
     kind = json.loads((root / "bench" / "traffic" / f"{traffic}.json").read_text())["kind"]
     like = "mushroom.mine" if kind == "mine" else "mushroom.serve"
     bench["workloads"].append(
-        {"name": name, "config": config, "traffic": traffic, "chips": 1, "why": "test"}
+        {"name": name, "config": config, "traffic": traffic, "chips": chips, "why": "test"}
     )
     for m in bench["end_to_end"] + bench["per_layer"]:
         if like in m.get("workloads", []):
@@ -69,14 +75,16 @@ def add_cell(root: pathlib.Path, name: str, config: str, traffic: str) -> None:
     path.write_text(json.dumps(bench))
 
 
-@pytest.fixture
-def tiny_layout(tmp_path):
-    """A copy of the layout with the tiny configuration, traffic and cells
-    ``tiny.mine`` (MRGanter+ on the jnp steps), ``tiny.cbo`` (MRCbo on the
-    fused Pallas kernels, as ``mushroom.mine`` runs), ``tiny.mine2`` (two
-    simulated parts, at a threshold low enough that one part's closure
-    differs from the whole's) and ``tiny.serve``."""
-    root = tmp_path / "layout"
+def make_tiny_layout(root: pathlib.Path) -> pathlib.Path:
+    """A copy of the layout at ``root`` with the tiny configuration,
+    traffic and cells ``tiny.mine`` (MRGanter+ on the jnp steps),
+    ``tiny.cbo`` (MRCbo on the fused Pallas kernels, as ``mushroom.mine``
+    runs), ``tiny.mine2`` (two simulated parts, at a threshold low enough
+    that one part's closure differs from the whole's), ``tiny.mine4x1``
+    (four parts on four chips, on the kernels, as ``census.mine.4chip``
+    runs), ``tiny.serve``, and two cells a run refuses: ``tiny.parts2chips4``
+    (two parts on four chips) and ``tiny.mesh2of4`` (two chips, meant for a
+    host with more)."""
     shutil.copytree(BENCH, root / "bench", ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(ROOT / "BENCHMARK.json", root)
     (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
@@ -91,8 +99,23 @@ def tiny_layout(tmp_path):
     add_cell(root, "tiny.mine", "tiny", "tiny_mine")
     add_cell(root, "tiny.cbo", "tiny", "tiny_cbo_kernel")
     add_cell(root, "tiny.mine2", "tiny", "tiny_mine_2parts")
+    add_cell(root, "tiny.mine4x1", "tiny", "tiny_mine_4x1", chips=4)
     add_cell(root, "tiny.serve", "tiny", "tiny_serve")
+    add_cell(root, "tiny.parts2chips4", "tiny", "tiny_mine_2parts", chips=4)
+    add_cell(root, "tiny.mesh2of4", "tiny", "tiny_mine_2parts", chips=2)
     return root
+
+
+@pytest.fixture
+def tiny_layout(tmp_path):
+    """:func:`make_tiny_layout` in a temporary directory."""
+    return make_tiny_layout(tmp_path / "layout")
+
+
+@pytest.fixture(scope="module")
+def tiny_layout_module(tmp_path_factory):
+    """:func:`make_tiny_layout` shared by one test module."""
+    return make_tiny_layout(tmp_path_factory.mktemp("tiny") / "layout")
 
 
 @pytest.fixture(scope="module")

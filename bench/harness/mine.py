@@ -7,6 +7,12 @@ first mine is set-up (it compiles, or loads from the compile cache, every
 frontier bucket the later ones use).  The window runs mines until
 ``seconds`` have passed and ends with the mine that crosses that line.
 
+A cell on one chip runs the traffic's ``parts`` as a plan simulated on that
+chip.  A cell on more than one chip mines over a real mesh (``--mesh``):
+one part on each of the cell's chips, with the AND-allreduce and the
+support psum between them.  ``fca.build_plan`` spans every device JAX
+sees, so a run whose plan is not a mesh of exactly the cell's chips exits.
+
 Every mine in the window is compared with the reference's iceberg lattice
 of the same context: the program returns intents only, and with the
 threshold applied a set of intents fixes their supports.
@@ -24,19 +30,42 @@ from harness import context, reference
 LIMITS = {"missing_concepts": 0, "extra_concepts": 0, "duplicate_concepts": 0}
 
 
-def argv(traffic: dict, min_support: int) -> list[str]:
-    """The ``fca mine`` arguments of a mining traffic file."""
+def argv(traffic: dict, min_support: int, chips: int) -> list[str]:
+    """The ``fca mine`` arguments of a mining traffic file for a cell on
+    ``chips`` chips; exits where a cell on more than one chip does not put
+    one part on each."""
+    parts = traffic.get("parts", 1)
+    if chips > 1 and parts != chips:
+        raise SystemExit(
+            f"bench: a cell on {chips} chips mines one part on each, "
+            f"its traffic has parts {parts}"
+        )
     out = [
         "mine",
         "--algorithm", traffic["algorithm"],
-        "--parts", str(traffic.get("parts", 1)),
+        "--parts", str(parts),
         "--backend", traffic["backend"],
         "--rounds", traffic.get("rounds", "sync"),
         "--min-support", str(min_support),
     ]
     if traffic.get("local_prune"):
         out.append("--local-prune")
+    if chips > 1:
+        out.append("--mesh")
     return out
+
+
+def mesh_devices(plan, chips: int) -> int:
+    """The devices ``plan`` mines on; exits where a cell on more than one
+    chip would not mine on a mesh of exactly its chips, one part each."""
+    mode = plan.describe()["mode"]
+    devices = 1 if plan.mesh is None else plan.mesh.devices.size
+    if chips > 1 and (mode != "mesh" or plan.n_parts != chips or devices != chips):
+        raise SystemExit(
+            f"bench: a cell on {chips} chips mines on a mesh of them, one part "
+            f"each; the plan is {mode} with {plan.n_parts} parts on {devices} devices"
+        )
+    return devices
 
 
 class MineJob:
@@ -49,7 +78,8 @@ class MineJob:
         self.min_support = context.resolve_min_support(
             run.traffic["min_support"], self.ctx.n_objects
         )
-        self.args = fca.parse_args(argv(run.traffic, self.min_support))
+        self.chips = run.cell["chips"]
+        self.args = fca.parse_args(argv(run.traffic, self.min_support, self.chips))
 
     def _mine(self) -> dict:
         import jax
@@ -59,6 +89,7 @@ class MineJob:
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench/mine"):
             plan = fca.build_plan(self.args)
+            self.run.counters["mesh_devices"] = mesh_devices(plan, self.chips)
             eng, res = fca._mine(
                 self.args, self.ctx, plan, self.run.traffic["backend"],
                 self.min_support,

@@ -72,9 +72,25 @@ def test_device_readers():
     run.units = [{}, {}]
     assert layout.reader("idle_share.mine")(run) == pytest.approx(100 * (1 - 5.5e-3 / 0.02))
     assert layout.reader("mine.kernel_ms")(run) == pytest.approx(3e-3 / 2 * 1e3)
+    # chip 0's all-gather, 2 ms over two chips and two mines
+    assert layout.reader("mine.collective_ms")(run) == pytest.approx(2e-3 / 2 / 2 * 1e3)
+    trace = _trace()
+    dev1 = trace["planes"][1]["lines"][0]["events"]
+    dev1 += [["all-to-all u32[2048,5]", 3 * MS, 3 * MS],
+             ["all-gather-start (u32[512,5], u32[2048,5])", 7 * MS, 1 * MS],
+             ["fusion u32[2048,5]", 9 * MS, 5 * MS]]
+    run.device = devtrace.reduce(trace)
+    assert layout.reader("mine.collective_ms")(run) == pytest.approx(
+        (2e-3 + 3e-3 + 1e-3) / 2 / 2 * 1e3
+    )
+    no_collective = _trace()
+    no_collective["planes"][0]["lines"][0]["events"].pop(2)
+    run.device = devtrace.reduce(no_collective)
+    assert layout.reader("mine.collective_ms")(run) is None
     run.device = None
     assert layout.reader("idle_share.mine")(run) is None
     assert layout.reader("mine.kernel_ms")(run) is None
+    assert layout.reader("mine.collective_ms")(run) is None
 
 
 @pytest.mark.parametrize("hlo, key", [

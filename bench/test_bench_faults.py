@@ -24,7 +24,8 @@ def test_traced_run_reads_correct(cell, tiny_layout, drive):
     out = drive(tiny_layout, cell, seconds=0.5 if cell == "tiny.serve" else 0.0, trace=1)
     assert out["correct"], out["checks"]
     want = {"tiny.mine": {"mine.compile_ms", "mine.host_blocked_ms"},
-            "tiny.serve": {"serve.service_ms", "serve.batch_fill", "serve.gen_lag_ms"}}
+            "tiny.serve": {"serve.service_ms", "serve.batch_fill", "serve.gen_lag_ms",
+                           "serve.query_p99_ms"}}
     assert set(out["metrics"]) == want[cell]
     assert out["device"]["window_s"] > 0
 

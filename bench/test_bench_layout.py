@@ -13,6 +13,7 @@ import sys
 
 import pytest
 
+from harness import mine
 from harness.layout import Layout, LayoutError
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -111,6 +112,31 @@ def test_peaks_table_keyed_by_device_kind():
     assert layout.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(LayoutError):
         layout.peaks("TPU v9 imaginary")
+
+
+# the ``fca mine`` arguments of each one-chip mining traffic file, with the
+# min support at 7, as the harness passed them before it could run a mesh
+ONE_CHIP_ARGV = {
+    "mrcbo_s05": ["mine", "--algorithm", "mrcbo", "--parts", "1", "--backend",
+                  "kernel", "--rounds", "sync", "--min-support", "7"],
+    "mrganterplus_s02": ["mine", "--algorithm", "mrganter+", "--parts", "1",
+                         "--backend", "kernel", "--rounds", "sync",
+                         "--min-support", "7", "--local-prune"],
+}
+
+
+@pytest.mark.parametrize("traffic", sorted(ONE_CHIP_ARGV))
+def test_one_chip_mining_argv_is_unchanged(traffic):
+    assert mine.argv(Layout(ROOT).traffic(traffic), 7, chips=1) == ONE_CHIP_ARGV[traffic]
+
+
+def test_four_chip_mining_argv_runs_a_mesh():
+    layout = Layout(ROOT)
+    want = ONE_CHIP_ARGV["mrganterplus_s02"].copy()
+    want[want.index("--parts") + 1] = "4"
+    assert mine.argv(layout.traffic("mrganterplus_s02_4x1"), 7, chips=4) == want + ["--mesh"]
+    with pytest.raises(SystemExit, match="parts 1"):
+        mine.argv(layout.traffic("mrganterplus_s02"), 7, chips=4)
 
 
 def test_new_traffic_file_is_found_by_name(tiny_layout):

@@ -30,7 +30,17 @@ def test_percentile_counts_shed_requests_as_over_every_limit():
 def test_query_p99_ms_reads_every_request():
     run = Run(cell={}, config={}, traffic={}, seed=0, traced=False)
     run.latencies_s = [i / 1000 for i in range(1, 201)]  # 1..200 ms
-    assert _read("query_p99_ms", run) == pytest.approx(198.0)
+    assert _read("serve.query_p99_ms", run) == pytest.approx(198.0)
+
+
+def test_query_p95_ms_reads_every_request():
+    run = Run(cell={}, config={}, traffic={}, seed=0, traced=False)
+    run.latencies_s = [i / 1000 for i in range(200, 0, -1)]  # 200..1 ms
+    assert _read("query_p95_ms", run) == pytest.approx(190.0)
+    run.latencies_s = [0.001] * 94 + [float("inf")] * 6
+    assert _read("query_p95_ms", run) == math.inf
+    run.latencies_s = []
+    assert _read("query_p95_ms", run) is None
 
 
 def test_mine_s_is_window_over_whole_mines():
